@@ -1,14 +1,14 @@
 """Benchmark suite runner: one module per paper table/figure.
 Prints ``name,us_per_call,derived`` CSV lines."""
 
-# The comm-volume benchmark compiles a dp=2 x tp=2 step, so the bench
-# process uses 4 host devices (NOT the dry-run's 512 — that stays local
-# to repro/launch/dryrun.py).
+# The comm-volume benchmark compiles a dp=2 x tp=2 step, so under
+# JAX_PLATFORMS=cpu the bench process fakes 4 host devices (NOT the
+# dry-run's 512 — that stays local to repro/launch/dryrun.py).
 import os
 
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=4 "
-    + os.environ.get("XLA_FLAGS", ""))
+from repro.launch.mesh import fake_cpu_devices
+
+fake_cpu_devices(4)
 
 import sys
 import traceback

@@ -10,10 +10,9 @@
    free their chunks the moment they complete (continuous batching).
 """
 
-import os
+from repro.launch.mesh import fake_cpu_devices
 
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
-                           + os.environ.get("XLA_FLAGS", ""))
+fake_cpu_devices(4)
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +27,9 @@ from repro.runtime.step import ChunkedRuntime, RuntimeOptions
 
 
 def compiled_demo(cfg):
-    mesh = make_smoke_mesh(2, 2)
+    # up to dp=2 x tp=2, as far as the devices present allow
+    tp = min(2, len(jax.devices()))
+    mesh = make_smoke_mesh(min(2, len(jax.devices()) // tp), tp)
     rt = ChunkedRuntime(model_class(cfg), cfg, mesh, RuntimeOptions())
     ps, _ = driver.init_state(rt, jax.random.key(0))
 

@@ -7,10 +7,13 @@ Default is a CPU-sized run; the full assignment-scale command is
     PYTHONPATH=src python examples/train_gpt_hetero.py \
         --layers 12 --d-model 768 --steps 300 --batch 8 --seq 512 \
         --dp 2 --tp 2            # ~100M params, a few hundred steps
+
+(``--dp``/``--tp`` above 1 need that many chips; under
+``JAX_PLATFORMS=cpu`` the example fakes them.)
 """
 
 import argparse
-import os
+import pathlib
 import time
 
 
@@ -21,14 +24,15 @@ def main():
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--dp", type=int, default=2)
-    ap.add_argument("--tp", type=int, default=2)
-    ap.add_argument("--checkpoint", default="/tmp/repro_gpt_ck")
+    ap.add_argument("--dp", type=int, default=1)
+    ap.add_argument("--tp", type=int, default=1)
+    ap.add_argument("--checkpoint", default=str(
+        pathlib.Path(__file__).resolve().parents[1] / "checkpoints" / "gpt"))
     args = ap.parse_args()
 
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.dp * args.tp} "
-        + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch.mesh import fake_cpu_devices
+
+    fake_cpu_devices(args.dp * args.tp)
 
     import jax
     import jax.numpy as jnp

@@ -36,14 +36,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
 
     def body(j, carry):
         acc, m, l = carry
-        # index the collapsed batch*heads dim with a size-1 Slice, not a
-        # raw int: this jax's load/store discharge rules only accept Slice
-        # or array indexers (an int scalar has no .shape and trips an
-        # AttributeError inside pallas/primitives.py).
-        k = pl.load(k_ref, (pl.dslice(0, 1),
-                            pl.dslice(j * block_k, block_k), slice(None)))[0]
-        v = pl.load(v_ref, (pl.dslice(0, 1),
-                            pl.dslice(j * block_k, block_k), slice(None)))[0]
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
         logits = q @ k.astype(jnp.float32).T  # [bq, bk]
         if causal:
             qpos = qi * block_q + jax.lax.broadcasted_iota(

@@ -8,17 +8,28 @@ import and then calls these.
 
 from __future__ import annotations
 
+import os
+
 import jax
 
 
+def fake_cpu_devices(n: int) -> None:
+    """Give XLA:CPU ``n`` devices, under ``JAX_PLATFORMS=cpu`` only.
+
+    Only the CPU backend can fake devices; on an accelerator a mesh takes
+    the chips present, and ``jax.make_mesh`` refuses one larger than that.
+    Call before JAX first asks for its devices.
+    """
+    if n > 1 and os.environ.get("JAX_PLATFORMS") == "cpu":
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={n} "
+            + os.environ.get("XLA_FLAGS", ""))
+
+
 def _mesh(shape, axes):
-    # axis_types / AxisType only exist on newer jax; older releases have
-    # Auto semantics by default, so the plain call is equivalent there.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape)
-        )
-    return jax.make_mesh(shape, axes)
+    # raises ValueError when the mesh needs more devices than are present
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

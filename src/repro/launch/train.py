@@ -1,20 +1,45 @@
 """Training launcher.
 
-  PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --smoke \\
-      --dp 2 --tp 2 --steps 50 --batch 8 --seq 128
+  PYTHONPATH=src python -m repro.launch.train --arch gpt2-paper-1b \\
+      --os-host-fraction 1.0 --batch 8 --seq 1024 --steps 20
 
-Runs the chunked ZeRO runtime end-to-end on the host devices (set
-``--devices N`` to fake a mesh on CPU), with the synthetic data pipeline,
-checkpointing, and metrics logging.  This is also the driver the
-end-to-end example wraps.
+Runs the chunked ZeRO runtime end-to-end on the devices present, with the
+synthetic data pipeline, checkpointing, and metrics logging.  The mesh
+takes ``--pods x --dp x --tp`` devices; under ``JAX_PLATFORMS=cpu`` the
+launcher fakes that many host devices (``--devices N`` overrides the
+count), while on an accelerator a mesh larger than the chips present is
+an error.  :func:`train` is the loop itself, shared by the CLI and
+``chip_smoke.py``.
 """
 
+from __future__ import annotations
+
 import argparse
+import dataclasses
 import os
+import pathlib
+import time
+from typing import Any, Callable
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser()
+def enable_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at a fixed path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache lives in ``<repo>/.jax_cache``.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir",
+                      str(REPO_ROOT / ".jax_cache"))
+
+
+def parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--arch", default="gpt2-paper-1b")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config")
@@ -22,10 +47,13 @@ def main() -> None:
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--pods", type=int, default=1)
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices (CPU)")
+                    help="host devices to fake under JAX_PLATFORMS=cpu "
+                         "(default: the mesh size)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--repeat-batch", action="store_true",
+                    help="train on the first batch every step (overfit check)")
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--remat", default="full", choices=["full", "dots", "none"])
     ap.add_argument("--gather-policy", default="layer", choices=["layer", "step"])
@@ -36,14 +64,26 @@ def main() -> None:
     ap.add_argument("--checkpoint-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
-    n_dev = args.devices or (args.pods * args.dp * args.tp)
-    if n_dev > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={n_dev} "
-            + os.environ.get("XLA_FLAGS", ""))
 
+@dataclasses.dataclass
+class TrainRun:
+    """What one :func:`train` call leaves behind."""
+
+    rt: Any  # ChunkedRuntime
+    pstores: Any
+    osstores: Any
+    losses: list[float]  # one per logged step
+    step_ms: list[float]  # host wall time of each logged step, synced
+    init_s: float  # init_state: compile + run
+    compile_s: float  # train step compile
+
+
+def train(args: argparse.Namespace, *,
+          log: Callable[[str], None] = print) -> TrainRun:
+    """Build the runtime from ``args`` (see :func:`parser`), initialise
+    the stores, compile the step and run ``args.steps`` steps."""
     import jax
     import jax.numpy as jnp
 
@@ -68,33 +108,55 @@ def main() -> None:
     n_params = sum(
         int(jnp.prod(jnp.array(s.shape)))
         for s in jax.tree.leaves(rt.model.param_specs()))
-    print(f"arch={cfg.name} mesh={dict(mesh.shape)} "
-          f"tp-local params={n_params/1e6:.1f}M "
-          f"layouts={[(k, v.store_shape, round(v.cmap.utilization, 3)) for k, v in rt.layouts.items()]}")
+    log(f"arch={cfg.name} mesh={dict(mesh.shape)} "
+        f"tp-local params={n_params/1e6:.1f}M "
+        f"layouts={[(k, v.store_shape, round(v.cmap.utilization, 3)) for k, v in rt.layouts.items()]}")
 
+    t0 = time.perf_counter()
+    pstores, osstores = jax.block_until_ready(
+        driver.init_state(rt, jax.random.key(args.seed)))
+    init_s = time.perf_counter() - t0
     shape = InputShape("cli", args.seq, args.batch, "train")
-    step_fn, _, _ = driver.build_train_step(rt, shape)
-    pstores, osstores = driver.init_state(rt, jax.random.key(args.seed))
+    step_fn, arg_specs, in_shardings = driver.build_train_step(rt, shape)
+    t0 = time.perf_counter()
+    step_fn = step_fn.lower(*arg_specs).compile()
+    compile_s = time.perf_counter() - t0
+    log(f"init_state {init_s:.1f} s  train-step compile {compile_s:.1f} s")
     next_batch = make_batch_fn(cfg, args.batch, args.seq, seed=args.seed)
 
-    import time
+    losses, step_ms = [], []
+    batch = None
     for step in range(args.steps):
         t0 = time.perf_counter()
-        batch = {k: jnp.asarray(v) for k, v in next_batch().items()
-                 if k != "mask"}
+        if batch is None or not args.repeat_batch:
+            batch = jax.device_put(
+                {k: v for k, v in next_batch().items() if k != "mask"},
+                in_shardings[2])
         pstores, osstores, metrics = step_fn(
             pstores, osstores, batch, jnp.int32(step))
         if step % args.log_every == 0:
-            jax.block_until_ready(metrics["loss"])
+            loss = float(metrics["loss"])  # waits for the step
             dt = time.perf_counter() - t0
-            print(f"step {step:4d}  loss {float(metrics['loss']):.4f}  "
-                  f"aux {float(metrics['aux_loss']):.4f}  {dt*1e3:.0f} ms")
+            losses.append(loss)
+            step_ms.append(dt * 1e3)
+            log(f"step {step:4d}  loss {loss:.4f}  "
+                f"aux {float(metrics['aux_loss']):.4f}  {dt*1e3:.0f} ms")
         if (args.checkpoint and args.checkpoint_every
                 and (step + 1) % args.checkpoint_every == 0):
             ckpt.save(rt, pstores, osstores, args.checkpoint, step=step + 1)
     if args.checkpoint:
         ckpt.save(rt, pstores, osstores, args.checkpoint, step=args.steps)
-        print(f"saved checkpoint to {args.checkpoint}")
+        log(f"saved checkpoint to {args.checkpoint}")
+    return TrainRun(rt, pstores, osstores, losses, step_ms, init_s, compile_s)
+
+
+def main(argv: list[str] | None = None) -> None:
+    from repro.launch.mesh import fake_cpu_devices
+
+    args = parser().parse_args(argv)
+    fake_cpu_devices(args.devices or (args.pods * args.dp * args.tp))
+    enable_compile_cache()
+    train(args)
 
 
 if __name__ == "__main__":

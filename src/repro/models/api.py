@@ -99,14 +99,18 @@ class Model:
 
     def init_params(self, key: jax.Array) -> dict:
         """Full (TP-local) param tree: {"stem": ..., groups: {name: stacked}}."""
+        stem_key, layer_keys = self.param_keys(key)
+        return {"stem": self.init_stem(stem_key),
+                "groups": {g.name: jax.vmap(g.init_layer)(layer_keys[g.name])
+                           for g in self.groups()}}
+
+    def param_keys(self, key: jax.Array) -> tuple[jax.Array, dict]:
+        """-> (stem key, {group name: [length] per-layer keys}) — the keys
+        :meth:`init_params` draws from, for callers that initialise one
+        layer at a time."""
         keys = jax.random.split(key, 1 + len(self.groups()))
-        params = {"stem": self.init_stem(keys[0])}
-        groups = {}
-        for i, g in enumerate(self.groups()):
-            lkeys = jax.random.split(keys[1 + i], g.length)
-            groups[g.name] = jax.vmap(g.init_layer)(lkeys)
-        params["groups"] = groups
-        return params
+        return keys[0], {g.name: jax.random.split(keys[1 + i], g.length)
+                         for i, g in enumerate(self.groups())}
 
     def param_specs(self) -> dict:
         """ShapeDtypeStructs of the TP-local param tree (no allocation)."""

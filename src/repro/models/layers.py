@@ -82,61 +82,11 @@ def all_axes(ctx: "AxisCtx") -> tuple[str, ...]:
     return tuple(a for a in (ctx.pod_axis, ctx.data_axis, ctx.model_axis) if a)
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=True):
-    """jax >= 0.6 spells this ``jax.shard_map(check_vma=...)``; older
-    releases only have ``jax.experimental.shard_map.shard_map`` with
-    ``check_rep``.  The vma helpers below already degrade to no-ops
-    there.  check_rep maps from check_vma: replication checking is what
-    gives the legacy psum its correct (identity-style) transpose in
-    training; serve paths that ask for check_vma=False get it off."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    return _legacy(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-
-
-def replicated_loss_compat(x, tp: int):
-    """Gradient-correctness shim for model-axis-replicated losses on jax
-    without the vma type system (legacy ``experimental.shard_map``).
-
-    A TP step computes the SAME total loss redundantly on every model
-    rank (activations are psum-combined, so each rank's scalar is the
-    full loss).  Under vma-typed jax the pcast/psum transpose rules know
-    the value is one invariant object and gradients come out right.  The
-    legacy transpose machinery instead differentiates each rank's copy
-    with cotangent 1 — the effective objective is ``tp * loss`` and every
-    gradient leaf (sharded and replicated alike) is tp-times too large.
-    Scaling the loss cotangent by ``1/tp`` on that path makes the
-    per-rank redundant copies sum to the true gradient; on vma-typed jax
-    (where ``jax.shard_map`` exists) this is the identity."""
-    if tp <= 1 or hasattr(jax, "shard_map"):
-        return x
-
-    @jax.custom_vjp
-    def _once(y):
-        return y
-
-    def fwd(y):
-        return y, None
-
-    def bwd(_, g):
-        return (g / tp,)
-
-    _once.defvjp(fwd, bwd)
-    return _once(x)
-
-
 def vary_to(x, axes: tuple[str, ...]):
     """pcast ``x`` to varying over ``axes`` (idempotent, typing-only)."""
     if not axes or not hasattr(x, "dtype"):
         return x
-    try:
-        vma = jax.typeof(x).vma
-    except Exception:
-        return x
+    vma = jax.typeof(x).vma
     missing = tuple(a for a in axes if a not in vma)
     if not missing:
         return x

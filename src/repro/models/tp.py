@@ -39,14 +39,12 @@ def _grad_psum(axis_name: str):
         return x, None
 
     def bwd(_, g):
-        # psum makes the cotangent invariant over the model axis; pvary
-        # restores the varying type expected for the store-shard input
-        # (the value is invariant in fact — all ranks hold the same sum).
-        # pvary is typing-only and absent on jax without the vma system.
+        # psum makes the cotangent invariant over the model axis; the
+        # pcast restores the varying type expected for the store-shard
+        # input (the value is invariant in fact — all ranks hold the same
+        # sum).
         g = jax.lax.psum(g, axis_name)
-        if hasattr(jax.lax, "pvary"):
-            g = jax.lax.pvary(g, axis_name)
-        return (g,)
+        return (jax.lax.pcast(g, axis_name, to="varying"),)
 
     f.defvjp(fwd, bwd)
     return f

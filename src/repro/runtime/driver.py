@@ -18,7 +18,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import InputShape, dtype_of
 from repro.core import zero
-from repro.models.layers import shard_map_compat as _shard_map
 from repro.runtime.step import ChunkedRuntime
 
 
@@ -41,27 +40,9 @@ def batch_axes(rt: ChunkedRuntime, global_batch: int):
     return tuple(axes)
 
 
-@functools.lru_cache(maxsize=1)
-def host_memory_kind_supported() -> bool:
-    """Whether this backend can place jit outputs in pinned_host memory.
-
-    True on TPU; False on the CPU backend (XLA:CPU lacks the
-    annotate_device_placement custom call), where host-offloaded OS chunk
-    groups fall back to device placement — the placement *policy* and its
-    group split still lower and are what the roofline reads.
-    """
-    try:
-        s = jax.sharding.SingleDeviceSharding(
-            jax.devices()[0], memory_kind="pinned_host")
-        jax.jit(lambda: jnp.zeros((8,), jnp.float32), out_shardings=s)()
-        return True
-    except Exception:
-        return False
-
-
 def _ns(rt, spec, *, host=False):
-    kw = {"memory_kind": "pinned_host"} if host and host_memory_kind_supported() else {}
-    return NamedSharding(rt.mesh, spec, **kw)
+    kind = rt.host_memory_kind if host else None
+    return NamedSharding(rt.mesh, spec, memory_kind=kind)
 
 
 def os_shardings(rt: ChunkedRuntime):
@@ -168,12 +149,12 @@ def decode_input_specs(rt: ChunkedRuntime, shape: InputShape):
 
 
 def _smap(rt, fn, in_specs, out_specs, *, check_vma=True):
-    # check_vma=True is required for correct psum/pvary gradient
+    # check_vma=True is required for correct psum/pcast gradient
     # transposes in training; serve paths (no autodiff) run with it off,
     # since batch-replicated decode (global_batch=1) produces values that
     # are invariant in fact but typed varying.
-    return _shard_map(fn, mesh=rt.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check_vma)
+    return jax.shard_map(fn, mesh=rt.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def build_train_step(rt: ChunkedRuntime, shape: InputShape):
@@ -194,8 +175,12 @@ def build_train_step(rt: ChunkedRuntime, shape: InputShape):
                                   is_leaf=lambda x: isinstance(x, P)))
     jf = jax.jit(f, in_shardings=in_shardings, out_shardings=out_shardings,
                  donate_argnums=(0, 1))
-    args = (rt.store_specs(), rt.os_specs(), bspecs,
-            jax.ShapeDtypeStruct((), jnp.int32))
+    # the specs carry their shardings (memory kinds included), so a
+    # lowering from them types the host-resident stores as a call would
+    args = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        (rt.store_specs(), rt.os_specs(), bspecs,
+         jax.ShapeDtypeStruct((), jnp.int32)), in_shardings)
     return jf, args, in_shardings
 
 
@@ -331,6 +316,12 @@ def slot_page_chunk_id(slot: int, total_layers: int, pages_per_slot: int,
 
 def init_state(rt: ChunkedRuntime, key):
     """Materialize param + optimizer-state chunk stores on the mesh."""
+    return build_init_state(rt)(key)
+
+
+def build_init_state(rt: ChunkedRuntime):
+    """-> jitted ``f(key) -> (pstores, osstores)`` placing every store with
+    its sharding (host-resident OS groups in pinned_host)."""
     ctx = rt.ctx
 
     def local_init(key):
@@ -339,54 +330,64 @@ def init_state(rt: ChunkedRuntime, key):
         # be bitwise identical across model ranks (router, MLA latent
         # projections, replicated kv, ...) — init both ways, select by
         # tp_axes.
-        params_rank = rt.model.init_params(
+        stem_r, layers_r = rt.model.param_keys(
             jax.random.fold_in(key, ctx.model_rank()))
-        params_shared = rt.model.init_params(key)
+        stem_s, layers_s = rt.model.param_keys(key)
 
         def select(axes, ranked, shared):
             return jax.tree.map(
                 lambda ax, a, b: b if ax is None else a,
                 axes, ranked, shared, is_leaf=lambda x: x is None)
 
-        params = {"stem": select(rt.tp_axes["stem"], params_rank["stem"],
-                                 params_shared["stem"]),
-                  "groups": {g.name: select(rt.tp_axes["groups"][g.name],
-                                            params_rank["groups"][g.name],
-                                            params_shared["groups"][g.name])
-                             for g in rt.model.groups()}}
         drank = (jax.lax.axis_index(ctx.data_axis)
                  if ctx.data_axis and ctx.dp > 1 else 0)
-        pstores = {}
-        stem_store = zero.flatten_to_store(rt.layouts["stem"], params["stem"])
-        pstores["stem"] = jax.lax.dynamic_slice_in_dim(
-            stem_store, drank, 1, axis=1)[None]
+
+        def local_store(lay, params):
+            store = zero.flatten_to_store(lay, params)  # [G, p, S]
+            return jax.lax.dynamic_slice_in_dim(store, drank, 1, axis=1)
+
+        stem = select(rt.tp_axes["stem"], rt.model.init_stem(stem_r),
+                      rt.model.init_stem(stem_s))
+        pstores = {"stem": local_store(rt.layouts["stem"], stem)[None]}
+        # one layer at a time (the same keys init_params vmaps over): the
+        # loop body compiles once, where a vmap over L does not
         for g in rt.model.groups():
-            lay = rt.layouts[g.name]
-            stacked = params["groups"][g.name]
-            store = jax.vmap(lambda t, _l=lay: zero.flatten_to_store(_l, t))(stacked)
-            pstores[g.name] = jax.lax.dynamic_slice_in_dim(
-                store, drank, 1, axis=2)[None]
+            def layer(keys, _g=g):
+                params = select(rt.tp_axes["groups"][_g.name],
+                                _g.init_layer(keys[0]), _g.init_layer(keys[1]))
+                return local_store(rt.layouts[_g.name], params)
+            pstores[g.name] = jax.lax.map(
+                layer, (layers_r[g.name], layers_s[g.name]))[None]
+
+        # OS stores start as (p32 = bf16 params, m = v = 0), built one
+        # slice at a time (a layer of a group store, a chunk group of the
+        # stem) and spilled as made, so no fp32 store materialises in HBM
+        def os_part(p, part):
+            def body(_, x):
+                p32 = x.astype(jnp.float32)
+                zeros = jnp.zeros_like(p32)
+                return None, tuple(rt.spill(y, part) for y in (p32, zeros, zeros))
+            _, ys = jax.lax.scan(body, None, p.reshape(p.shape[1:]))
+            return tuple(y.reshape((1,) + y.shape) for y in ys)
+
         osstores = {}
         for name, p in pstores.items():
-            gax = 1 if name == "stem" else 2
-            dev_g, host_g = rt.os_split(name)
-            p32 = p.astype(jnp.float32)
-            zeros = jnp.zeros_like(p32)
             # local stores keep the global rank ([1(tp), ..., G, 1, S]),
             # so the G axis index matches the global one
-            sl = lambda x, a, b: jax.lax.slice_in_dim(x, a, b, axis=gax)
-            osstores[name] = {
-                "p32": {"dev": sl(p32, 0, dev_g), "host": sl(p32, dev_g, dev_g + host_g)},
-                "m": {"dev": sl(zeros, 0, dev_g), "host": sl(zeros, dev_g, dev_g + host_g)},
-                "v": {"dev": sl(zeros, 0, dev_g), "host": sl(zeros, dev_g, dev_g + host_g)},
-            }
+            gax = 1 if name == "stem" else 2
+            dev_g, host_g = rt.os_split(name)
+            parts = {
+                part: os_part(jax.lax.slice_in_dim(p, lo, hi, axis=gax), part)
+                for part, lo, hi in (("dev", 0, dev_g),
+                                     ("host", dev_g, dev_g + host_g))}
+            osstores[name] = {k: {part: parts[part][i] for part in parts}
+                              for i, k in enumerate(("p32", "m", "v"))}
         return pstores, osstores
 
     p_ps = rt.store_pspecs()
     os_ps = rt.os_pspecs()
     f = _smap(rt, local_init, (P(),), (p_ps, os_ps))
-    jf = jax.jit(f, out_shardings=(param_shardings(rt), os_shardings(rt)))
-    return jf(key)
+    return jax.jit(f, out_shardings=(param_shardings(rt), os_shardings(rt)))
 
 
 def init_caches(rt: ChunkedRuntime, shape: InputShape):
@@ -418,8 +419,8 @@ def _smap_nullary(rt, fn, out_specs):
     def wrapper(dummy):
         return fn()
     return functools.partial(
-        _shard_map(wrapper, mesh=rt.mesh, in_specs=(P(),),
-                   out_specs=out_specs, check_vma=True),
+        jax.shard_map(wrapper, mesh=rt.mesh, in_specs=(P(),),
+                      out_specs=out_specs, check_vma=True),
         jnp.zeros((), jnp.int32))
 
 

@@ -63,6 +63,26 @@ class RuntimeOptions:
     xent_block: int = 0  # blockwise LM-head cross-entropy (0 = off)
 
 
+def map_slices(fn, carry, xs=()):
+    """In-place ``carry[k][:, i] = fn(*carry[:, i], *xs[:, i])[k]`` for
+    every index ``i`` of axis 1 of local ``[1, N, ...]`` stores (the layers
+    of a group store, the chunk groups of the stem).  A ``fori_loop`` over
+    ``i`` keeps one slice of each store live in HBM at a time; ``carry``
+    may live in pinned_host.  Slices keep their singleton axes."""
+    drop = lambda a: a.reshape(a.shape[1:])  # the collapsed tp axis
+    xs = [drop(x) for x in xs]
+
+    def body(i, acc):
+        sl = lambda a: jax.lax.dynamic_index_in_dim(a, i, axis=0)
+        ys = fn(*map(sl, acc), *map(sl, xs))
+        return tuple(jax.lax.dynamic_update_index_in_dim(a, y, i, axis=0)
+                     for a, y in zip(acc, ys))
+
+    out = jax.lax.fori_loop(0, carry[0].shape[1], body,
+                            tuple(map(drop, carry)))
+    return tuple(o.reshape((1,) + o.shape) for o in out)
+
+
 class ChunkedRuntime:
     """Binds (model, mesh, options) into lowered/lowerable step functions."""
 
@@ -82,6 +102,12 @@ class ChunkedRuntime:
             moe_combine_first=self.opt.moe_combine_first,
             xent_block=self.opt.xent_block,
         )
+        # Memory kind of the host-resident OS groups.  XLA:CPU (the test
+        # backend) cannot place buffers in pinned_host, so there — and only
+        # there — those groups are ordinary device buffers; on an
+        # accelerator a failed pinned_host placement raises.
+        platform = mesh.devices.flat[0].platform
+        self.host_memory_kind = None if platform == "cpu" else "pinned_host"
         self.model: Model = model_cls(cfg, self.ctx)
         self.tp_axes = self.model.tp_axes()
         self._build_layouts()
@@ -216,11 +242,7 @@ class ChunkedRuntime:
                 (x, aux), _ = jax.lax.scan(self._remat(body2),
                                            vary_tree((x, aux), va), flat)
         loss = self.model.head_loss(stem, x, batch)
-        # the total is replicated over the model axis (every TP rank
-        # computes the full loss); on legacy jax its cotangent must carry
-        # 1/tp or all gradients come out tp-times too large
-        from repro.models.layers import replicated_loss_compat
-        return replicated_loss_compat(loss + aux, self.ctx.tp), (loss, aux)
+        return loss + aux, (loss, aux)
 
     def train_step_fn(self) -> Callable:
         """Returns f(pstores, osstores, batch, step) -> (pstores', os', metrics),
@@ -283,10 +305,25 @@ class ChunkedRuntime:
         return loss, aux / n, grads
 
     # -------------------------------------------------------------- optimizer
+    def fetch(self, x, part: str):
+        """Bring a slice of OS part ``part`` ("dev" | "host") into HBM."""
+        if part == "host" and self.host_memory_kind is not None:
+            return jax.device_put(x, jax.memory.Space.Device)
+        return x
+
+    def spill(self, x, part: str):
+        """Return a slice of OS part ``part`` to where that part lives."""
+        if part == "host" and self.host_memory_kind is not None:
+            return jax.device_put(x, jax.memory.Space.Host)
+        return x
+
     def _adam_update(self, pstores, osstores, grads, step_idx):
-        """Chunked ADAM on the local shard; grad-fp16 chunks are converted
-        to fp32 on the fly (Section 6.2); host-resident OS groups round-trip
-        through pinned_host (device-aware placement, Section 8.2)."""
+        """Chunked ADAM on the local shard, one slice (a layer of a group
+        store, a chunk group of the stem) at a time: grad-bf16 chunks are
+        converted to fp32 per slice (Section 6.2), and host-resident OS
+        groups are fetched from and spilled back to pinned_host slice by
+        slice (device-aware placement, Section 8.2).  HBM holds one
+        slice's fp32 state, not the store."""
         opt = self.opt
         b1, b2 = opt.betas
         t = step_idx.astype(jnp.float32) + 1.0
@@ -310,42 +347,34 @@ class ChunkedRuntime:
             p32 = p32 - opt.lr * upd
             return p32, m, v
 
+        def update_slice(p32, m, v, p, g, part):
+            p32, m, v = update_part(self.fetch(p32, part), self.fetch(m, part),
+                                    self.fetch(v, part), g.astype(jnp.float32))
+            return (self.spill(p32, part), self.spill(m, part),
+                    self.spill(v, part), p32.astype(p.dtype))
+
         new_p, new_os = {}, {}
         for name, p in pstores.items():
             gax = 1 if name == "stem" else 2
             os_n = osstores[name]
-            g32 = grads[name].astype(jnp.float32)
             dev_g = os_n["p32"]["dev"].shape[gax]
-            g_dev = jax.lax.slice_in_dim(g32, 0, dev_g, axis=gax)
-            g_host = jax.lax.slice_in_dim(g32, dev_g, g32.shape[gax], axis=gax)
-            # device-resident OS groups
-            p32d, md, vd = update_part(os_n["p32"]["dev"], os_n["m"]["dev"],
-                                       os_n["v"]["dev"], g_dev)
-            # host-resident OS groups: fetch -> update -> evict (the compiled
-            # analogue of chunk h2d/d2h moves around ADAM)
-            if g_host.shape[gax] > 0:
-                from repro.runtime.driver import host_memory_kind_supported
-                if host_memory_kind_supported():
-                    fetch = lambda x: jax.device_put(
-                        x, jax.sharding.TransferToMemoryKind("device"))
-                    spill = lambda x: jax.device_put(
-                        x, jax.sharding.TransferToMemoryKind("pinned_host"))
-                else:  # CPU backend: offload is a placement no-op
-                    fetch = spill = lambda x: x
-                p32h, mh, vh = update_part(fetch(os_n["p32"]["host"]),
-                                           fetch(os_n["m"]["host"]),
-                                           fetch(os_n["v"]["host"]), g_host)
-                p32h_s, mh_s, vh_s = spill(p32h), spill(mh), spill(vh)
-            else:
-                p32h, mh_s, vh_s = os_n["p32"]["host"], os_n["m"]["host"], os_n["v"]["host"]
-                p32h_s = p32h
-            new_os[name] = {"p32": {"dev": p32d, "host": p32h_s},
-                            "m": {"dev": md, "host": mh_s},
-                            "v": {"dev": vd, "host": vh_s}}
-            # updated param fp32 -> param fp16 chunks (next iteration's params)
-            pd = p32d.astype(p.dtype)
-            ph = p32h.astype(p.dtype)
-            new_p[name] = jax.lax.concatenate([pd, ph], dimension=gax)
+            parts, p_parts = {}, []
+            for part, lo, hi in (("dev", 0, dev_g), ("host", dev_g, p.shape[gax])):
+                state = tuple(os_n[k][part] for k in ("p32", "m", "v"))
+                if hi == lo:  # empty part: nothing to update or concatenate
+                    parts[part] = state
+                    continue
+                sl = lambda x: jax.lax.slice_in_dim(x, lo, hi, axis=gax)
+                *parts[part], p_new = map_slices(
+                    functools.partial(update_slice, part=part),
+                    (*state, sl(p)), (sl(grads[name]),))
+                p_parts.append(p_new)
+            new_os[name] = {k: {part: parts[part][i] for part in parts}
+                            for i, k in enumerate(("p32", "m", "v"))}
+            # updated param fp32 -> param bf16 chunks (next iteration's
+            # params); both parts were converted on the device
+            new_p[name] = (p_parts[0] if len(p_parts) == 1
+                           else jax.lax.concatenate(p_parts, dimension=gax))
         return new_p, new_os
 
     # --------------------------------------------------------------- serving

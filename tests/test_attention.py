@@ -10,7 +10,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import BaseConfig, MoEConfig
 from repro.models import layers as L
 from repro.models import mla as MLA
-from repro.models.layers import shard_map_compat
 
 
 def _qkv(key, b, sq, sk, h, kv, d, dtype=jnp.float32):
@@ -92,8 +91,8 @@ def test_tp_attention_matches_single_device(h, kv, tp):
             y_dec, cache2 = L.attention_decode(p, x[:, i:i + 1], cache2, i, cfg, ctx)
         return y_fwd, y_pre, y_dec
 
-    f = jax.jit(shard_map_compat(run, mesh=_mesh(tp), in_specs=(P(),),
-                              out_specs=P(), check_vma=False))
+    f = jax.jit(jax.shard_map(run, mesh=_mesh(tp), in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
     y_fwd, y_pre, y_dec = f(x)
     np.testing.assert_allclose(np.asarray(y_fwd), np.asarray(ref), atol=2e-4)
     np.testing.assert_allclose(np.asarray(y_pre), np.asarray(ref), atol=2e-4)
@@ -132,8 +131,8 @@ def test_prefill_then_decode_continues():
                                           S + i, cfg, ctx)
         return y
 
-    f = jax.jit(shard_map_compat(run, mesh=_mesh(tp), in_specs=(P(),),
-                              out_specs=P(), check_vma=False))
+    f = jax.jit(jax.shard_map(run, mesh=_mesh(tp), in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
     y = f(x)
     np.testing.assert_allclose(np.asarray(y[:, 0]), np.asarray(ref[:, -1]),
                                atol=2e-4)
@@ -182,8 +181,8 @@ def test_mla_decode_matches_fwd(tp):
             y, cache = MLA.mla_decode(p, x[:, i:i + 1], cache, i, cfg, ctx)
         return y
 
-    f = jax.jit(shard_map_compat(run, mesh=_mesh(tp), in_specs=(P(),),
-                              out_specs=P(), check_vma=False))
+    f = jax.jit(jax.shard_map(run, mesh=_mesh(tp), in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
     y = f(x)
     np.testing.assert_allclose(np.asarray(y[:, 0]), np.asarray(ref[:, -1]),
                                atol=2e-4)

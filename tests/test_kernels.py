@@ -68,3 +68,51 @@ def test_flash_matches_scan_twin():
     b = scan_attention(q, k, v, causal=True, block=64)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
+
+
+def _adam_args():
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(3), 4)
+    shape = (3, 1, 2 * BLOCK)  # a [G, p, S] chunk store
+    p32 = jax.random.normal(k1, shape)
+    m = jax.random.normal(k2, shape) * 0.01
+    v = jnp.abs(jax.random.normal(k3, shape)) * 0.01
+    g = jax.random.normal(k4, shape).astype(jnp.bfloat16)
+    hp = dict(lr=1e-3, beta1=0.9, beta2=0.95, eps=1e-8, weight_decay=0.0,
+              bias_corr1=0.1, bias_corr2=0.05)
+    return (p32, m, v, g), hp
+
+
+def _flash_args():
+    k1, k2, k3 = jax.random.split(jax.random.key(4), 3)
+    return tuple(jax.random.normal(k, (1, 512, 2, 64)) for k in (k1, k2, k3)), {}
+
+
+_OPS = {
+    "chunked_adam": (_adam_args, ref.adam_ref),
+    "flash_attention": (_flash_args, ref.flash_attention_ref),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_OPS))
+def test_ops_refuse_a_non_tpu_backend(name):
+    """The public wrappers never swap in a reference or the interpreter:
+    off TPU, a call without ``interpret=True`` raises."""
+    from repro.kernels import ops
+
+    assert jax.default_backend() != "tpu"
+    args, kw = _OPS[name][0]()
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        getattr(ops, name)(*args, **kw)
+
+
+@pytest.mark.parametrize("name", sorted(_OPS))
+def test_ops_interpret_matches_ref(name):
+    from repro.kernels import ops
+
+    args, kw = _OPS[name][0]()
+    got = getattr(ops, name)(*args, interpret=True, **kw)
+    want = _OPS[name][1](*args, **kw)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5)

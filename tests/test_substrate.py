@@ -106,3 +106,99 @@ def test_train_hlo_has_chunked_collectives():
     st = parse_collectives(txt)
     assert "all-gather" in st.by_kind
     assert "reduce-scatter" in st.by_kind
+
+
+# ---------------------------------------------------------------------------
+# host-resident optimizer state (os_host_fraction)
+# ---------------------------------------------------------------------------
+
+
+def _launch(*extra):
+    from repro.launch import train as launcher
+
+    args = launcher.parser().parse_args(
+        ["--arch", "gpt2-paper-1b", "--smoke", "--dp", "2", "--batch", "4",
+         "--seq", "32", "--steps", "3", "--repeat-batch", *extra])
+    return launcher.train(args, log=lambda s: None)
+
+
+@pytest.mark.parametrize("fraction", [0.5, 1.0])
+def test_os_host_fraction_keeps_losses(fraction):
+    """Where the optimizer-state groups live changes no arithmetic: the
+    launcher's losses at fractions 0.5 and 1.0 equal those at 0.0."""
+    want = _launch("--os-host-fraction", "0.0").losses
+    got = _launch("--os-host-fraction", str(fraction)).losses
+    assert len(got) == 3 and got == want
+    assert got[-1] < got[0]
+
+
+@pytest.mark.parametrize("fraction", [0.0, 0.5, 1.0])
+def test_init_state_matches_unsplit_init(fraction):
+    """init_state packs ``model.init_params`` into the param stores bit for
+    bit and starts the OS stores at (p32 = params, m = v = 0), however the
+    OS groups are split between device and host."""
+    cfg = get_config("gpt2-paper-1b", smoke=True)
+    rt = ChunkedRuntime(model_class(cfg), cfg, make_smoke_mesh(1, 1),
+                        RuntimeOptions(os_host_fraction=fraction))
+    key = jax.random.key(0)
+    ps, oss = driver.init_state(rt, key)
+
+    from repro.core import zero
+
+    @jax.jit
+    def unsplit(key):
+        # tp=1: sharded leaves draw from fold_in(key, rank 0), replicated
+        # leaves from key itself
+        ranked = rt.model.init_params(jax.random.fold_in(key, 0))
+        shared = rt.model.init_params(key)
+        pick = lambda axes, a, b: jax.tree.map(
+            lambda ax, x, y: y if ax is None else x, axes, a, b,
+            is_leaf=lambda x: x is None)
+        out = {"stem": zero.flatten_to_store(rt.layouts["stem"], pick(
+            rt.tp_axes["stem"], ranked["stem"], shared["stem"]))[None]}
+        for g in rt.model.groups():
+            tree = pick(rt.tp_axes["groups"][g.name],
+                        ranked["groups"][g.name], shared["groups"][g.name])
+            out[g.name] = jax.vmap(lambda t, _l=rt.layouts[g.name]:
+                                   zero.flatten_to_store(_l, t))(tree)[None]
+        return out
+
+    for name, want in unsplit(key).items():
+        gax = 1 if name == "stem" else 2
+        assert ps[name].dtype == want.dtype
+        assert bool(jnp.array_equal(ps[name], want)), name
+        p32 = want.astype(jnp.float32)
+        for k, ref in (("p32", p32), ("m", jnp.zeros_like(p32)),
+                       ("v", jnp.zeros_like(p32))):
+            dev, host = oss[name][k]["dev"], oss[name][k]["host"]
+            assert (dev.shape[gax], host.shape[gax]) == rt.os_split(name)
+            got = jnp.concatenate([dev, host], axis=gax)
+            assert bool(jnp.array_equal(got, ref)), (name, k)
+
+
+@pytest.mark.parametrize("platform,kind", [("cpu", None), ("tpu", "pinned_host")])
+def test_host_placement_follows_mesh_platform(platform, kind):
+    """The runtime reads the platform from its own mesh (not from
+    ``jax.devices()``, which is the CPU here): only an XLA:CPU mesh keeps
+    host-resident OS groups in device memory."""
+    from types import SimpleNamespace
+
+    cfg = get_config("gpt2-paper-1b", smoke=True)
+    mesh = SimpleNamespace(
+        devices=np.array([[SimpleNamespace(platform=platform)]]),
+        axis_names=("data", "model"), shape={"data": 1, "model": 1})
+    rt = ChunkedRuntime(model_class(cfg), cfg, mesh,
+                        RuntimeOptions(os_host_fraction=1.0))
+    assert rt.host_memory_kind == kind
+
+
+def test_cpu_mesh_keeps_host_groups_on_device():
+    cfg = get_config("gpt2-paper-1b", smoke=True)
+    rt = ChunkedRuntime(model_class(cfg), cfg, make_smoke_mesh(1, 1),
+                        RuntimeOptions(os_host_fraction=1.0))
+    kinds = {sh["host"].memory_kind
+             for st in driver.os_shardings(rt).values() for sh in st.values()}
+    assert kinds == {"device"}
+    _, oss = driver.init_state(rt, jax.random.key(0))
+    assert {oss[n][k]["host"].sharding.memory_kind
+            for n in oss for k in oss[n]} == {"device"}

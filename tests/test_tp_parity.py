@@ -10,7 +10,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config, model_class
 from repro.core import zero
 from repro.launch.mesh import make_smoke_mesh
-from repro.models.layers import AxisCtx, shard_map_compat
+from repro.models.layers import AxisCtx
 from repro.runtime.step import ChunkedRuntime, RuntimeOptions
 
 TP = 2
@@ -82,7 +82,7 @@ def test_loss_and_grad_parity(arch):
         return jax.lax.psum(vary_to(tot, ("data", "model")),
                             ("data", "model")) / TP
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         jax.value_and_grad(loss2), mesh=mesh,
         in_specs=(rt.store_pspecs(),
                   {"tokens": P(), "labels": P(), "global_tokens": P()}),

@@ -11,7 +11,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import zero
 from repro.core.tracer import RuntimeMemoryTracer
-from repro.models.layers import shard_map_compat
 
 
 @st.composite
@@ -54,7 +53,7 @@ def test_gather_and_grad_reduce_scatter():
         val, g = jax.value_and_grad(loss)(local)
         return jax.lax.psum(val, "data") / 4.0, g
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P(None, "data", None),),
         out_specs=(P(), P(None, "data", None)), check_vma=True))
     val, g = f(store)
