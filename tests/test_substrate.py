@@ -176,26 +176,27 @@ def test_init_state_matches_unsplit_init(fraction):
             assert bool(jnp.array_equal(got, ref)), (name, k)
 
 
-@pytest.mark.parametrize("platform,kind", [("cpu", None), ("tpu", "pinned_host")])
-def test_host_placement_follows_mesh_platform(platform, kind):
-    """The runtime reads the platform from its own mesh (not from
-    ``jax.devices()``, which is the CPU here): only an XLA:CPU mesh keeps
-    host-resident OS groups in device memory."""
+def test_host_placement_follows_mesh_platform():
+    """The runtime reads the platform from its own mesh, not from
+    ``jax.devices()`` (the CPU here): a TPU mesh puts host-resident OS
+    groups in pinned_host."""
     from types import SimpleNamespace
 
     cfg = get_config("gpt2-paper-1b", smoke=True)
     mesh = SimpleNamespace(
-        devices=np.array([[SimpleNamespace(platform=platform)]]),
+        devices=np.array([[SimpleNamespace(platform="tpu")]]),
         axis_names=("data", "model"), shape={"data": 1, "model": 1})
     rt = ChunkedRuntime(model_class(cfg), cfg, mesh,
                         RuntimeOptions(os_host_fraction=1.0))
-    assert rt.host_memory_kind == kind
+    assert rt.host_memory_kind == "pinned_host"
 
 
 def test_cpu_mesh_keeps_host_groups_on_device():
+    """Only an XLA:CPU mesh keeps host-resident OS groups in device memory."""
     cfg = get_config("gpt2-paper-1b", smoke=True)
     rt = ChunkedRuntime(model_class(cfg), cfg, make_smoke_mesh(1, 1),
                         RuntimeOptions(os_host_fraction=1.0))
+    assert rt.host_memory_kind is None
     kinds = {sh["host"].memory_kind
              for st in driver.os_shardings(rt).values() for sh in st.values()}
     assert kinds == {"device"}
