@@ -9,7 +9,10 @@ takes ``--pods x --dp x --tp`` devices; under ``JAX_PLATFORMS=cpu`` the
 launcher fakes that many host devices (``--devices N`` overrides the
 count), while on an accelerator a mesh larger than the chips present is
 an error.  :func:`train` is the loop itself, shared by the CLI and
-``chip_smoke.py``.
+``chip_smoke.py``.  ``--profile DIR`` writes a ``jax.profiler`` trace of
+steps 2 to 4 under DIR: each step is a ``train`` step span, its batch
+preparation and transfer a ``feed`` span, beside the device's ops, whose
+names carry the train step's named scopes (``runtime/step.py``).
 """
 
 from __future__ import annotations
@@ -64,6 +67,8 @@ def parser() -> argparse.ArgumentParser:
     ap.add_argument("--checkpoint-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="write a jax.profiler trace of steps 2 to 4 under DIR")
     return ap
 
 
@@ -124,23 +129,34 @@ def train(args: argparse.Namespace, *,
     log(f"init_state {init_s:.1f} s  train-step compile {compile_s:.1f} s")
     next_batch = make_batch_fn(cfg, args.batch, args.seq, seed=args.seed)
 
+    if args.profile and args.steps < 3:
+        raise ValueError("--profile traces steps 2 to 4: give --steps 3 or more")
     losses, step_ms = [], []
     batch = None
+    profiled = range(2, min(5, args.steps)) if args.profile else range(0)
     for step in range(args.steps):
+        if profiled and step == profiled[0]:
+            jax.profiler.start_trace(args.profile)
         t0 = time.perf_counter()
-        if batch is None or not args.repeat_batch:
-            batch = jax.device_put(
-                {k: v for k, v in next_batch().items() if k != "mask"},
-                in_shardings[2])
-        pstores, osstores, metrics = step_fn(
-            pstores, osstores, batch, jnp.int32(step))
-        if step % args.log_every == 0:
-            loss = float(metrics["loss"])  # waits for the step
-            dt = time.perf_counter() - t0
-            losses.append(loss)
-            step_ms.append(dt * 1e3)
-            log(f"step {step:4d}  loss {loss:.4f}  "
-                f"aux {float(metrics['aux_loss']):.4f}  {dt*1e3:.0f} ms")
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            with jax.profiler.TraceAnnotation("feed"):
+                if batch is None or not args.repeat_batch:
+                    batch = jax.device_put(
+                        {k: v for k, v in next_batch().items() if k != "mask"},
+                        in_shardings[2])
+            pstores, osstores, metrics = step_fn(
+                pstores, osstores, batch, jnp.int32(step))
+            if step % args.log_every == 0:
+                loss = float(metrics["loss"])  # waits for the step
+                dt = time.perf_counter() - t0
+                losses.append(loss)
+                step_ms.append(dt * 1e3)
+                log(f"step {step:4d}  loss {loss:.4f}  "
+                    f"aux {float(metrics['aux_loss']):.4f}  {dt*1e3:.0f} ms")
+        if profiled and step == profiled[-1]:
+            jax.block_until_ready(metrics)
+            jax.profiler.stop_trace()
+            log(f"profile of steps {profiled[0]} to {step} written under {args.profile}")
         if (args.checkpoint and args.checkpoint_every
                 and (step + 1) % args.checkpoint_every == 0):
             ckpt.save(rt, pstores, osstores, args.checkpoint, step=step + 1)

@@ -180,6 +180,7 @@ class ChunkedRuntime:
         return out
 
     # ------------------------------------------------------- gather plumbing
+    @jax.named_scope("gather")
     def _gather_tree(self, name: str, local_store, *, dtype):
         """local_store: [G,1,S] (layer or stem slice) -> param pytree with
         replicated-grad sync applied."""
@@ -207,7 +208,8 @@ class ChunkedRuntime:
         """Runs inside shard_map. pstores: local stores with leading 1s."""
         model, ctx, cdtype = self.model, self.ctx, dtype_of(self.cfg.compute_dtype)
         stem = self._gather_tree("stem", pstores["stem"][0], dtype=cdtype)
-        x, extras = model.embed(stem, batch)
+        with jax.named_scope("embed"):
+            x, extras = model.embed(stem, batch)
         aux = jnp.float32(0.0)
         for g in model.groups():
             x, extras = model.between_groups(g.name, x, extras, stem, batch)
@@ -217,18 +219,21 @@ class ChunkedRuntime:
                 def body(carry, layer_store, _g=g, _va=va):
                     cx, caux = carry
                     params = self._gather_tree(_g.name, layer_store, dtype=cdtype)
-                    y, a = _g.apply(params, cx, extras, ctx)
+                    with jax.named_scope("layers"):
+                        y, a = _g.apply(params, cx, extras, ctx)
                     return vary_tree((y, caux + jnp.float32(a)), _va), None
                 (x, aux), _ = jax.lax.scan(self._remat(body),
                                            vary_tree((x, aux), va), store)
             else:  # "step": one gather for the whole group, then scan
                 lay = self.layouts[g.name]
-                if ctx.data_axis:
-                    flat = zero.gather_store(store, ctx.data_axis)  # [L, G*p*S]
-                else:
-                    flat = store.reshape(store.shape[0], -1)
+                with jax.named_scope("gather"):
+                    if ctx.data_axis:
+                        flat = zero.gather_store(store, ctx.data_axis)  # [L, G*p*S]
+                    else:
+                        flat = store.reshape(store.shape[0], -1)
                 axes = self.tp_axes["groups"][g.name]
 
+                @jax.named_scope("gather")
                 def unflatten_layer(fl, _lay=lay, _axes=axes):
                     params = zero.unflatten_from_flat(_lay, fl, dtype=cdtype)
                     return tpmod.sync_replicated_grads(
@@ -237,11 +242,14 @@ class ChunkedRuntime:
                 va = all_axes(ctx)
                 def body2(carry, fl, _g=g, _uf=unflatten_layer, _va=va):
                     cx, caux = carry
-                    y, a = _g.apply(_uf(fl), cx, extras, ctx)
+                    params = _uf(fl)
+                    with jax.named_scope("layers"):
+                        y, a = _g.apply(params, cx, extras, ctx)
                     return vary_tree((y, caux + jnp.float32(a)), _va), None
                 (x, aux), _ = jax.lax.scan(self._remat(body2),
                                            vary_tree((x, aux), va), flat)
-        loss = self.model.head_loss(stem, x, batch)
+        with jax.named_scope("head"):
+            loss = self.model.head_loss(stem, x, batch)
         return loss + aux, (loss, aux)
 
     def train_step_fn(self) -> Callable:
@@ -249,7 +257,10 @@ class ChunkedRuntime:
         to be wrapped in shard_map by the caller (see ``shard_train_step``)."""
         ctx = self.ctx
 
-        def step(pstores, osstores, batch, step_idx):
+        # the name is the module's: JAX's compile-cache key leaves op_name
+        # metadata out, so a change to the named scopes alone needs a new
+        # name, or a shared cache serves the executable with the old op_names
+        def train_step(pstores, osstores, batch, step_idx):
             if self.opt.accum_steps > 1:
                 loss, aux, grads = self._accum_grads(pstores, batch)
             else:
@@ -271,7 +282,7 @@ class ChunkedRuntime:
             new_p, new_os = self._adam_update(pstores, osstores, grads, step_idx)
             return new_p, new_os, metrics
 
-        return step
+        return train_step
 
     def _accum_grads(self, pstores, batch):
         """Gradient accumulation over microbatches (scan over batch
@@ -317,6 +328,7 @@ class ChunkedRuntime:
             return jax.device_put(x, jax.memory.Space.Host)
         return x
 
+    @jax.named_scope("adam")
     def _adam_update(self, pstores, osstores, grads, step_idx):
         """Chunked ADAM on the local shard, one slice (a layer of a group
         store, a chunk group of the stem) at a time: grad-bf16 chunks are

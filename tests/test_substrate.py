@@ -132,6 +132,25 @@ def test_os_host_fraction_keeps_losses(fraction):
     assert got[-1] < got[0]
 
 
+def test_profile_writes_step_and_feed_spans(tmp_path):
+    """``--profile DIR`` leaves a profiler trace of steps 2 to 4 whose host
+    spans name each step (``train``) and its batch transfer (``feed``)."""
+    _launch("--steps", "4", "--profile", str(tmp_path))
+    files = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert len(files) == 1
+    data = jax.profiler.ProfileData.from_file(str(files[0]))
+    steps, names = set(), set()
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    names.add(ev.name)
+                    if ev.name == "train":
+                        steps.add(dict(ev.stats)["step_num"])
+    assert {"train", "feed"} <= names
+    assert steps == {2, 3}
+
+
 @pytest.mark.parametrize("fraction", [0.0, 0.5, 1.0])
 def test_init_state_matches_unsplit_init(fraction):
     """init_state packs ``model.init_params`` into the param stores bit for
