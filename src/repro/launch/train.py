@@ -126,7 +126,8 @@ def train(args: argparse.Namespace, *,
     t0 = time.perf_counter()
     step_fn = step_fn.lower(*arg_specs).compile()
     compile_s = time.perf_counter() - t0
-    log(f"init_state {init_s:.1f} s  train-step compile {compile_s:.1f} s")
+    log(f"init_state {init_s:.1f} s  train-step compile {compile_s:.1f} s  "
+        f"slices fetched ahead {rt.pipelined_slices()}")
     next_batch = make_batch_fn(cfg, args.batch, args.seq, seed=args.seed)
 
     if args.profile and args.steps < 3:

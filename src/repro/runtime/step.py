@@ -83,6 +83,47 @@ def map_slices(fn, carry, xs=()):
     return tuple(o.reshape((1,) + o.shape) for o in out)
 
 
+def stream_slices(fn, fetch, spill, stores, outs, xs=(), after=()):
+    """``map_slices`` for stores that live off the device: slice ``i + 1``
+    of each of ``stores`` is fetched into HBM while slice ``i`` is updated
+    and spilled back.  ``fn(*store_slices, *out_slices, *x_slices)``
+    returns the new slices of ``stores``, then of ``outs`` (stores in HBM),
+    as ``map_slices``'s ``fn`` does for ``carry = (*stores, *outs)``.
+    A prologue fetches slice 0 and the last slice is peeled, so nothing is
+    fetched that is not updated; HBM holds two slices of each store.
+    The first fetch waits for the arrays ``after``.  Each store's fetch
+    of slice ``i + 1`` ends before its spill of slice ``i`` starts (XLA
+    cannot tell the two apart in one buffer), so what overlaps is one
+    store's spill with another store's fetch."""
+    drop = lambda a: a.reshape(a.shape[1:])
+    stores, outs, xs = ([drop(a) for a in t] for t in (stores, outs, xs))
+    k, n = len(stores), stores[0].shape[0]
+    # else XLA may start this part's first fetch beside another part's
+    # loop, and hold both parts' slices in HBM at once
+    stores, _ = jax.lax.optimization_barrier((stores, after))
+    sl = lambda a, i: jax.lax.dynamic_index_in_dim(a, i, axis=0)
+    put = lambda a, y, i: jax.lax.dynamic_update_index_in_dim(a, y, i, axis=0)
+
+    def update(i, st, ou, cur):
+        ys = fn(*cur, *(sl(a, i) for a in ou), *(sl(x, i) for x in xs))
+        st = tuple(put(a, spill(y), i) for a, y in zip(st, ys[:k]))
+        ou = tuple(put(a, y, i) for a, y in zip(ou, ys[k:]))
+        return st, ou
+
+    def body(i, acc):
+        st, ou, cur = acc
+        nxt = tuple(fetch(sl(a, i + 1)) for a in st)
+        return (*update(i, st, ou, cur), nxt)
+
+    first = tuple(fetch(sl(a, 0)) for a in stores)
+    st, ou, cur = jax.lax.fori_loop(0, n - 1, body, (tuple(stores), tuple(outs), first))
+    st, ou = update(n - 1, st, ou, cur)
+    # else XLA moves the reshape above the peeled slice's spill, whose
+    # write then indexes axis 1, and a host copy must index the major axis
+    st = jax.lax.optimization_barrier(st)
+    return tuple(o.reshape((1,) + o.shape) for o in (*st, *ou))
+
+
 class ChunkedRuntime:
     """Binds (model, mesh, options) into lowered/lowerable step functions."""
 
@@ -158,6 +199,19 @@ class ChunkedRuntime:
         host = int(round(g * self.opt.os_host_fraction))
         host = min(max(host, 0), g)
         return g - host, host
+
+    def pipelined_slices(self) -> dict[str, int]:
+        """Per store, the slices a step fetches ahead of their update: the
+        host part's loop (``stream_slices``) fetches all but its first
+        slice while the one before is updated and spilled.  Zero for a
+        store with no host part or a one-slice one (the stem's chunk
+        groups when there is one)."""
+        out = {}
+        for name in self.layouts:
+            _, host_g = self.os_split(name)
+            n = host_g if name == "stem" else self.group_lengths[name]
+            out[name] = n - 1 if host_g else 0
+        return out
 
     def os_specs(self) -> dict:
         """OS stores: {"name": {"p32"|"m"|"v": {"dev": SDS, "host": SDS}}}."""
@@ -316,9 +370,9 @@ class ChunkedRuntime:
         return loss, aux / n, grads
 
     # -------------------------------------------------------------- optimizer
-    def fetch(self, x, part: str):
-        """Bring a slice of OS part ``part`` ("dev" | "host") into HBM."""
-        if part == "host" and self.host_memory_kind is not None:
+    def fetch(self, x):
+        """Bring a slice of a host-resident OS part into HBM."""
+        if self.host_memory_kind is not None:
             return jax.device_put(x, jax.memory.Space.Device)
         return x
 
@@ -334,8 +388,9 @@ class ChunkedRuntime:
         store, a chunk group of the stem) at a time: grad-bf16 chunks are
         converted to fp32 per slice (Section 6.2), and host-resident OS
         groups are fetched from and spilled back to pinned_host slice by
-        slice (device-aware placement, Section 8.2).  HBM holds one
-        slice's fp32 state, not the store."""
+        slice (device-aware placement, Section 8.2), the next slice
+        fetched while one is updated (``stream_slices``).  HBM holds one
+        or two slices' fp32 state, not the store."""
         opt = self.opt
         b1, b2 = opt.betas
         t = step_idx.astype(jnp.float32) + 1.0
@@ -359,14 +414,19 @@ class ChunkedRuntime:
             p32 = p32 - opt.lr * upd
             return p32, m, v
 
-        def update_slice(p32, m, v, p, g, part):
-            p32, m, v = update_part(self.fetch(p32, part), self.fetch(m, part),
-                                    self.fetch(v, part), g.astype(jnp.float32))
-            return (self.spill(p32, part), self.spill(m, part),
-                    self.spill(v, part), p32.astype(p.dtype))
+        def update_slice(p32, m, v, p, g):
+            p32, m, v = update_part(p32, m, v, g.astype(jnp.float32))
+            return p32, m, v, p32.astype(p.dtype)
 
         new_p, new_os = {}, {}
-        for name, p in pstores.items():
+        # a host part's first fetch waits for the last update of the host
+        # part before it, whose spill it overlaps: HBM holds one host
+        # part's slices at a time.  A host stem goes first, its one slice
+        # spilled while the layers stream
+        streamed = ()
+        stem_first = lambda n: not (n == "stem" and self.os_split(n)[1])
+        for name in sorted(pstores, key=stem_first):
+            p = pstores[name]
             gax = 1 if name == "stem" else 2
             os_n = osstores[name]
             dev_g = os_n["p32"]["dev"].shape[gax]
@@ -377,9 +437,15 @@ class ChunkedRuntime:
                     parts[part] = state
                     continue
                 sl = lambda x: jax.lax.slice_in_dim(x, lo, hi, axis=gax)
-                *parts[part], p_new = map_slices(
-                    functools.partial(update_slice, part=part),
-                    (*state, sl(p)), (sl(grads[name]),))
+                if part == "host":
+                    *parts[part], p_new = stream_slices(
+                        update_slice, self.fetch,
+                        functools.partial(self.spill, part=part),
+                        state, (sl(p),), (sl(grads[name]),), streamed)
+                    streamed = p_new
+                else:
+                    *parts[part], p_new = map_slices(
+                        update_slice, (*state, sl(p)), (sl(grads[name]),))
                 p_parts.append(p_new)
             new_os[name] = {k: {part: parts[part][i] for part in parts}
                             for i, k in enumerate(("p32", "m", "v"))}

@@ -6,10 +6,12 @@ pinned_host, must pass the TPU compiler and fit one chip's HBM.  The
 topology is described inside a fixture, never while a module is imported,
 so only the worker that runs these tests loads the TPU library.  (The
 whole gpt2-paper-1b train step compiles too, in about three minutes on a
-CPU host: too slow for this suite; ``chip_smoke.py`` runs it on the chip.)
+CPU host: too slow for this suite; ``chip_smoke.py`` runs it on the chip.
+Its smoke widths compile in seconds, and show the ADAM loop's schedule.)
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,3 +100,70 @@ def test_init_state_fits_one_chip_with_state_on_host(rt_1b):
             for n in os_sh for k in os_sh[n]} == {"pinned_host"}
     n_elems = sum(int(np.prod(s.shape)) for s in rt_1b.store_specs().values())
     assert c.memory_analysis().host_output_size_in_bytes >= 3 * 4 * n_elems
+
+
+def _adam_loop_transfers(text):
+    """Host transfers of the scheduled ADAM while bodies of an executable's
+    text, in schedule order: ("in" | "out", "start" | "done", start name)
+    for host->HBM dynamic slices and HBM->host dynamic update slices."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name, comps[head.group(1)] = head.group(1), []
+        elif name:
+            comps[name].append(line)
+    bodies = [m.group(1) for body in comps.values() for line in body
+              if " while(" in line and "/adam/while" in line
+              for m in [re.search(r"body=%([^,\s]+)", line)]]
+    out = []
+    for body in bodies:
+        seq = []
+        for line in comps[body]:
+            m = re.match(r"\s*%(\S+) = .*? (dynamic-(?:update-)?slice)-"
+                         r"(start|done)\((%[^,)]+)", line)
+            if m is None:
+                continue
+            op, phase = m.group(2), m.group(3)
+            start = m.group(1) if phase == "start" else m.group(4)[1:]
+            if phase == "start" and "S(5)" not in line[:m.start(2)]:
+                continue  # no operand in host memory (space 5)
+            seq.append(("out" if "update" in op else "in", phase, start))
+        starts = {s for _, phase, s in seq if phase == "start"}
+        out.append([t for t in seq if t[2] in starts])
+    return out
+
+
+def test_adam_loop_overlaps_host_reads_and_writes(topo):
+    """gpt2-paper-1b at smoke widths and four layers (a loop of one
+    iteration is unrolled), all optimizer state on host: in the scheduled
+    ADAM loop a host->HBM slice is in flight while an HBM->host slice is
+    (the next slice is fetched while this one is spilled), and the step
+    fits one chip."""
+    from repro.configs import get_config, model_class
+    from repro.configs.base import InputShape
+    from repro.runtime import driver
+    from repro.runtime.step import ChunkedRuntime, RuntimeOptions
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    cfg = get_config("gpt2-paper-1b", smoke=True).replace(num_layers=4)
+    rt = ChunkedRuntime(model_class(cfg), cfg, mesh,
+                        RuntimeOptions(os_host_fraction=1.0))
+    jf, args, _ = driver.build_train_step(rt, InputShape("t", 32, 2, "train"))
+    c = jf.lower(*args).compile()
+    assert _hbm_bytes(c) <= HBM_LIMIT_BYTES
+    loops = _adam_loop_transfers(c.as_text())
+    assert loops and all(loops)
+
+    def interval(seq, direction, start):
+        at = [i for i, t in enumerate(seq) if t[0] == direction and t[2] == start]
+        return at[0], at[-1]
+
+    overlapped = False
+    for seq in loops:
+        reads = [interval(seq, "in", s) for d, p, s in seq if d == "in" and p == "start"]
+        writes = [interval(seq, "out", s) for d, p, s in seq if d == "out" and p == "start"]
+        overlapped |= any(r0 < w1 and r1 > w0
+                          for r0, r1 in reads for w0, w1 in writes)
+    assert overlapped, loops
