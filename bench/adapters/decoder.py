@@ -9,8 +9,6 @@ program's model does, so one tree of names serves both.
 
 from __future__ import annotations
 
-import dataclasses
-
 from bench import weights
 from bench.reference import decoder as ref
 
@@ -40,23 +38,6 @@ def program_config(name: str, cfg: dict):
 
 
 def install_weights(rt, cfg: dict) -> None:
-    """Make ``driver.init_state(rt, weights.base_key(seed))`` draw the
-    benchmark's weights for ``seed``."""
-    import jax
-
-    model = rt.model
-    want = jax.tree.map(lambda s: (s.shape, s.dtype), model.param_specs())
-    groups = model.groups
-    n = {g.name: g.length for g in groups()}
-    model.param_keys = lambda key: (
-        weights.stem_key(key),
-        {name: weights.layer_keys(key, length) for name, length in n.items()})
-    model.init_stem = lambda key: ref.init_stem(cfg, key)
-    model.groups = lambda: [
-        dataclasses.replace(g, init_layer=lambda key: ref.init_layer(cfg, key))
-        for g in groups()]
-    got = jax.tree.map(lambda s: (s.shape, s.dtype), model.param_specs())
-    if got != want:
-        raise ValueError("the reference's parameter tree differs from the "
-                         f"program's:\n{got}\n{want}")
-
+    """Make ``weights.init_state(rt, seed)`` draw the benchmark's weights
+    for ``seed``."""
+    weights.install(rt, ref, cfg)

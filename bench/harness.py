@@ -7,12 +7,17 @@ traffic mix (``bench/traffic/<traffic>.json``: batch, sequence,
 placement, optimizer, corpus) and its chips.  The limits of its
 comparison are in ``bench/limits/<cell>.json``, and each per-layer metric
 is read by ``bench/metrics/<metric>.py``.  Adding a cell, a configuration
-or a metric adds files; nothing here names one.
+or a metric adds files; nothing here names one.  The family describes
+the configuration's shape: the keys its file must hold, its layer groups
+in model order (which must be the program's), each group's layers and
+weights, and the model FLOPs per token (``bench/reference/decoder.py``
+lists the protocol); the adapter maps the file to the program's config
+and hooks the weights into it.
 
 A run:
 
 1. builds the program's runtime for the cell, packs the benchmark's
-   weights for ``--seed`` into its stores (``driver.init_state``) and
+   weights for ``--seed`` into its stores (``weights.init_state``) and
    compiles its train step (``driver.build_train_step``);
 2. runs the first ``checked_steps`` (3) steps through that same compiled
    step, on batches that all differ, and reads the program's fp32 state
@@ -45,9 +50,7 @@ RUNS = ROOT / ".bench_runs"  # traces; listed in .gitignore
 
 TRAFFIC_KEYS = {"rows", "seq", "dp", "os_host_fraction", "optimizer", "remat",
                 "gather_policy", "corpus", "checked_steps"}
-CONFIG_KEYS = {"family", "source", "hidden_size", "intermediate_size",
-               "num_attention_heads", "num_key_value_heads", "head_dim",
-               "num_hidden_layers", "vocab_size"}
+CONFIG_KEYS = {"family", "source"}  # and the keys the family lists as its own
 
 
 class SpecError(ValueError):
@@ -86,6 +89,7 @@ class Cell:
     limits: dict
     per_layer: list  # the per_layer entries of BENCHMARK.json this cell reports
     end_to_end: list
+    fam: Any  # the reference family, bench/reference/<family>.py
 
 
 def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
@@ -102,6 +106,11 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
         raise SpecError(f"{spec_file} has {len(confs)} configs named {w['config']!r}")
     cfg = _read_json(root / confs[0]["file"], f"config {w['config']}")
     _need(cfg, CONFIG_KEYS, f"config {w['config']}")
+    try:
+        fam = importlib.import_module(f"bench.reference.{cfg['family']}")
+    except ModuleNotFoundError as e:
+        raise SpecError(f"config {w['config']}: no family {cfg['family']!r} ({e})") from None
+    _need(cfg, fam.CONFIG_KEYS, f"config {w['config']}")
     traffic = _read_json(root / "bench" / "traffic" / f"{w['traffic']}.json",
                          f"traffic {w['traffic']}")
     _need(traffic, TRAFFIC_KEYS, f"traffic {w['traffic']}")
@@ -113,7 +122,15 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     reports = lambda m: name in m.get("workloads", [name])
     return Cell(name, w["chips"], w["config"], cfg, traffic, limits,
                 [m for m in spec.get("per_layer", []) if reports(m)],
-                [m for m in spec.get("end_to_end", []) if reports(m)])
+                [m for m in spec.get("end_to_end", []) if reports(m)], fam)
+
+
+def check_groups(rt, fam, cfg: dict) -> None:
+    """The family's layer groups must be the program's, in name, length
+    and order: leaves are named and weights keyed by them."""
+    want, got = list(fam.groups(cfg)), list(rt.group_lengths.items())
+    if [tuple(g) for g in want] != got:
+        raise SpecError(f"the family's layer groups {want} are not the program's {got}")
 
 
 def use_compile_cache() -> None:
@@ -139,8 +156,8 @@ def summarizer(rt, fam, cfg: dict):
     leaf of every layer and of the stem: the norms of p32 - p0, of m and
     of v, and the largest gap between the bf16
     working copy and bf16(p32).  p0 is the benchmark's initial weights,
-    made again from the key.  Host-resident slices are brought to HBM
-    one layer at a time."""
+    made again from the key by each group's own init on its own keys.
+    Host-resident slices are brought to HBM one layer at a time."""
     import jax
     import jax.numpy as jnp
 
@@ -199,11 +216,11 @@ def summarizer(rt, fam, cfg: dict):
                                   fam.init_stem(cfg, weights.stem_key(wkey)))
                 continue
             n = pst[name].shape[1]
-            keys = weights.layer_keys(wkey, n)
+            keys = weights.group_keys(wkey, fam.groups(cfg))[name]
 
-            def layer(i, _lay=lay, _args=args, _keys=keys):
+            def layer(i, _lay=lay, _args=args, _keys=keys, _name=name):
                 sl = lambda a: jax.lax.dynamic_index_in_dim(a[0], i, 0, keepdims=False)
-                return stats(_lay, *_args(sl), fam.init_layer(cfg, _keys[i]))
+                return stats(_lay, *_args(sl), fam.init_layer(cfg, _keys[i], group=_name))
             out[name] = jax.lax.map(layer, jnp.arange(n))
         return out
 
@@ -304,9 +321,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, *, t_start: float,
     from repro.runtime import driver
     from repro.runtime.step import ChunkedRuntime, RuntimeOptions
 
-    fam_name = cell.cfg["family"]
-    fam = importlib.import_module(f"bench.reference.{fam_name}")
-    adapter = importlib.import_module(f"bench.adapters.{fam_name}")
+    fam = cell.fam
+    adapter = importlib.import_module(f"bench.adapters.{cell.cfg['family']}")
     tr, opt = cell.traffic, cell.traffic["optimizer"]
     rows, seq, dp = tr["rows"], tr["seq"], tr["dp"]
     tokens_per_step = rows * seq
@@ -316,9 +332,10 @@ def run(name: str, seed: int, seconds: float, trace: bool, *, t_start: float,
         remat=tr["remat"], gather_policy=tr["gather_policy"],
         os_host_fraction=tr["os_host_fraction"], lr=opt["lr"],
         betas=tuple(opt["betas"]), eps=opt["eps"]))
+    check_groups(rt, fam, cell.cfg)
     adapter.install_weights(rt, cell.cfg)
     phases = {"start": time.perf_counter() - t_start}
-    pst, ost = jax.block_until_ready(driver.init_state(rt, weights.base_key(seed)))
+    pst, ost = jax.block_until_ready(weights.init_state(rt, seed))
     phases["init_state"] = time.perf_counter() - t_start
     jf, specs, in_sh = driver.build_train_step(rt, InputShape("bench", seq, rows, "train"))
     compiled = jf.lower(*specs).compile()
@@ -378,17 +395,24 @@ def run(name: str, seed: int, seconds: float, trace: bool, *, t_start: float,
     setup_s = t_w0 - t_start - check_s
     deadline = t_w0 + seconds
     finished = [t_w0]  # when each step's loss was seen, for the log
+    # the host's spans, by which a trace names the device's idle gaps
+    span = jax.profiler.TraceAnnotation
     while True:  # one step in flight while the next is fed
-        pst, ost, met = step(pst, ost, feed(k), idx(k))
+        with span("feed"):
+            args = feed(k), idx(k)
+        with span("dispatch"):
+            pst, ost, met = step(pst, ost, *args)
         k += 1
         if pending is not None:
-            window_losses.append(pending.block_until_ready())
+            with span("wait_loss"):
+                window_losses.append(pending.block_until_ready())
             finished.append(time.perf_counter())
             done += 1
         pending = met["loss"]
         if time.perf_counter() >= deadline:
             break
-    window_losses.append(pending.block_until_ready())
+    with span("wait_loss"):
+        window_losses.append(pending.block_until_ready())
     done += 1
     t_end = time.perf_counter()
     finished.append(t_end)
@@ -410,13 +434,13 @@ def run(name: str, seed: int, seconds: float, trace: bool, *, t_start: float,
 
     metrics = {}
     if trace:
-        from bench import flops, trace as trace_mod
+        from bench import trace as trace_mod
 
         tr_data = trace_mod.load(tdir, [d.id for d in used])
         device["busy_s"] = tr_data.busy_s()
         device["window_s"] = window_s
         info = RunInfo(cell, len(used), kind, peaks.get(kind, {}),
-                       flops.train_flops_per_token(cell.cfg, seq), tokens_per_s, done,
+                       fam.flops_per_token(cell.cfg, seq), tokens_per_s, done,
                        window_s, compiled, tr_data)
         for m in cell.per_layer:
             reader = importlib.import_module(f"bench.metrics.{m['name']}")

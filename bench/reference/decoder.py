@@ -13,6 +13,21 @@ layer at a time; the caller sets ``jax.default_matmul_precision``.  The
 benchmark's weights come from :func:`init_stem` and :func:`init_layer`.
 ``q`` rounds the operands of each matrix product; the plain reference
 passes the identity, the lower-precision control a rounding.
+
+A reference family is a module of this package that describes its
+configurations' shape to the harness:
+
+* ``CONFIG_KEYS``: the keys a configuration file of the family must hold;
+* ``groups(cfg)``: its layer groups in model order, [(name, length)],
+  named as the program names its own;
+* ``init_stem(cfg, key)``, ``embed(cfg, stem, tokens)`` and
+  ``head_loss_sum(cfg, stem, x, labels, q)``;
+* ``init_layer(cfg, key, group=...)`` and ``layer(cfg, p, x, q, group=...)``
+  for a layer of a named group; a layer returns x, or (x, aux) where aux
+  is a scalar term of the objective;
+* ``flops_per_token(cfg, seq)``: model FLOPs of training per token.
+
+This family has one group, ``layers``, of ``num_hidden_layers``.
 """
 
 from __future__ import annotations
@@ -21,6 +36,19 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from bench import flops
+
+CONFIG_KEYS = {"hidden_size", "intermediate_size", "num_attention_heads",
+               "num_key_value_heads", "head_dim", "num_hidden_layers", "vocab_size"}
+
+
+def groups(cfg: dict) -> list[tuple[str, int]]:
+    return [("layers", cfg["num_hidden_layers"])]
+
+
+def flops_per_token(cfg: dict, seq: int) -> float:
+    return flops.train_flops_per_token(cfg, seq)
 
 
 def dims(cfg: dict) -> dict:
@@ -54,7 +82,7 @@ def init_stem(cfg: dict, key, dtype=jnp.bfloat16) -> dict:
     return stem
 
 
-def init_layer(cfg: dict, key, dtype=jnp.bfloat16) -> dict:
+def init_layer(cfg: dict, key, dtype=jnp.bfloat16, *, group: str = "layers") -> dict:
     k = dims(cfg)
     d, f, qd, kvd = k["d"], k["f"], k["h"] * k["hd"], k["kv"] * k["hd"]
     ks = jax.random.split(key, 7)
@@ -108,12 +136,11 @@ _ACT = {"gelu_pytorch_tanh": lambda x: jax.nn.gelu(x, approximate=True),
         "silu": jax.nn.silu}
 
 
-def layer(cfg: dict, p: dict, x, q=lambda t: t):
-    """One block on the residual stream x [B, S, d] (float32)."""
+def attention(cfg: dict, a: dict, h, q=lambda t: t):
+    """Causal self-attention of the normed stream h [B, S, d], before the
+    output projection's residual add."""
     k = dims(cfg)
-    b, s, _ = x.shape
-    a = p["attn"]
-    h = _norm(k, x, p["norm_attn"], p.get("norm_attn_b"))
+    b, s, _ = h.shape
     qh, kh, vh = (_mm(h, a[w], q) for w in ("wq", "wk", "wv"))
     if k["bias"]:
         qh, kh, vh = qh + a["bq"], kh + a["bk"], vh + a["bv"]
@@ -126,13 +153,24 @@ def layer(cfg: dict, p: dict, x, q=lambda t: t):
     causal = jnp.tril(jnp.ones((s, s), bool))
     probs = jax.nn.softmax(jnp.where(causal, logits, -jnp.inf), axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", q(probs), q(vh)).reshape(b, s, -1)
-    x = x + _mm(o, a["wo"], q)
-    m = p["mlp"]
-    h = _norm(k, x, p["norm_mlp"], p.get("norm_mlp_b"))
+    return _mm(o, a["wo"], q)
+
+
+def mlp(cfg: dict, m: dict, h, q=lambda t: t):
+    """The MLP of the normed stream h, gated where ``w_gate`` is given."""
     up = _mm(h, m["w_up"], q)
-    act = _ACT[k["act"]]
-    hid = act(_mm(h, m["w_gate"], q)) * up if k["gated"] else act(up)
-    return x + _mm(hid, m["w_down"], q)
+    act = _ACT[cfg["hidden_act"]]
+    hid = act(_mm(h, m["w_gate"], q)) * up if "w_gate" in m else act(up)
+    return _mm(hid, m["w_down"], q)
+
+
+def layer(cfg: dict, p: dict, x, q=lambda t: t, *, group: str = "layers"):
+    """One block on the residual stream x [B, S, d] (float32)."""
+    k = dims(cfg)
+    h = _norm(k, x, p["norm_attn"], p.get("norm_attn_b"))
+    x = x + attention(cfg, p["attn"], h, q)
+    h = _norm(k, x, p["norm_mlp"], p.get("norm_mlp_b"))
+    return x + mlp(cfg, p["mlp"], h, q)
 
 
 def embed(cfg: dict, stem: dict, tokens):

@@ -1,8 +1,13 @@
 """Three plain Adam steps of a reference model, layer by layer.
 
-The forward pass keeps each layer's input; the backward pass walks the
-layers in reverse, takes each layer's gradient by ``jax.vjp`` and updates
-that layer at once, so one layer's gradient is alive at a time.  The
+The forward pass walks the family's layer groups in model order and keeps
+each layer's input; the backward pass walks the layers in reverse, takes
+each layer's gradient by ``jax.vjp`` and updates that layer at once, so
+one layer's gradient is alive at a time.  A layer that returns
+``(x, aux)`` adds ``aux`` to the objective: its cotangent is 1, as the
+program differentiates its loss plus the layers' aux terms; the loss
+reported is the language-model loss alone.  Leaves are named
+``<group>.<i>.<path>`` and ``stem.<path>``.  The
 output head runs over blocks of rows.  The fp32 master copy stays on the
 device and Adam's moments, one layer at a time, in ``memory_kind``
 (``pinned_host`` on a chip), so the whole state never has to fit in HBM
@@ -82,20 +87,28 @@ def train3(fam, cfg: dict, job: dict, seed: int, batches: list[dict], *,
     and return the summary :mod:`bench.compare` reads."""
     t0 = time.perf_counter()
     q = _fp8 if lower else (lambda t: t)
-    n_layers = cfg["num_hidden_layers"]
+    groups = fam.groups(cfg)
     dev = jax.devices()[0]
     to_dev = functools.partial(jax.device_put, device=dev)
     with jax.default_matmul_precision("highest"):
         # the bf16 weights come out of a program of their own, so that
         # their rounding cannot be dropped before the cast to float32
         to_f32 = jax.jit(_f32)
-        init_layer = lambda k, f=jax.jit(lambda k: fam.init_layer(cfg, k)): to_f32(f(k))
+        made = {g: jax.jit(functools.partial(fam.init_layer, cfg, group=g)) for g, _ in groups}
+        init_layer = lambda g, k: to_f32(made[g](k))
         init_stem = lambda k, f=jax.jit(lambda k: fam.init_stem(cfg, k)): to_f32(f(k))
+
         # each pass sees the bf16 rounding of the master copy; the
         # gradient with respect to it is the gradient Adam applies
-        fwd = jax.jit(lambda p, x: fam.layer(cfg, _f32(p), x, q))
-        bwd = jax.jit(lambda p, x, dy: jax.vjp(
-            lambda p, x: fam.layer(cfg, p, x, q), _f32(p), x)[1](dy))
+        def backward(p, x, dy, g):
+            out, pull = jax.vjp(lambda p, x: fam.layer(cfg, p, x, q, group=g), _f32(p), x)
+            if isinstance(out, tuple):  # (x, aux): aux enters the objective once
+                return pull((dy, jnp.ones_like(out[1])))
+            return pull(dy)
+
+        fwd = {g: jax.jit(lambda p, x, g=g: fam.layer(cfg, _f32(p), x, q, group=g))
+               for g, _ in groups}
+        bwd = {g: jax.jit(functools.partial(backward, g=g)) for g, _ in groups}
         emb = jax.jit(lambda st, tok: fam.embed(cfg, _f32(st), tok))
         emb_bwd = jax.jit(lambda st, tok, dy: jax.vjp(
             lambda st: fam.embed(cfg, st, tok), _f32(st))[1](dy)[0])
@@ -112,9 +125,11 @@ def train3(fam, cfg: dict, job: dict, seed: int, batches: list[dict], *,
         scale = jax.jit(lambda a, s: jax.tree.map(lambda x: x * s, a))
 
         wkey = weights.weight_key(seed)
-        keys = weights.layer_keys(wkey, n_layers)
+        keys = weights.group_keys(wkey, groups)
+        # every layer of the model in order: (group, index in group, key)
+        order = [(g, i, keys[g][i]) for g, n in groups for i in range(n)]
         stem = init_stem(weights.stem_key(wkey))
-        layers = [init_layer(keys[i]) for i in range(n_layers)]
+        layers = [init_layer(g, k) for g, _, k in order]
         zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
         if host:
             zeros = jax.jit(lambda t: jax.device_put(
@@ -129,8 +144,9 @@ def train3(fam, cfg: dict, job: dict, seed: int, batches: list[dict], *,
             stem_b, layers_b = _to_bf16(stem), [_to_bf16(p) for p in layers]
             stem_h = to_f32(stem_b)  # the head's weights, as the passes see them
             xs = [emb(stem_b, tokens)]
-            for p in layers_b:
-                xs.append(fwd(p, xs[-1]))
+            for (g, _, _), p in zip(order, layers_b):
+                y = fwd[g](p, xs[-1])
+                xs.append(y[0] if isinstance(y, tuple) else y)
             x = xs.pop()
             shape = x.shape
             x, lab = x.reshape(-1, shape[-1]), labels.reshape(-1)
@@ -143,12 +159,13 @@ def train3(fam, cfg: dict, job: dict, seed: int, batches: list[dict], *,
             out["loss"].append(loss / n_tok)
             dx = jnp.concatenate(dxs).reshape(shape) / n_tok
             del x, dxs
-            for i in reversed(range(n_layers)):
-                g, dx = bwd(layers_b[i], xs.pop(), dx)
+            for j in reversed(range(len(order))):
+                g, i, _ = order[j]
+                grad, dx = bwd[g](layers_b[j], xs.pop(), dx)
                 if t == 1:
-                    out["g1"].update(leaf_norms(g, f"layers.{i}"))
-                layers[i], *mv[i] = adam(layers[i], *mv[i], g, float(t))
-                del g
+                    out["g1"].update(leaf_norms(grad, f"{g}.{i}"))
+                layers[j], *mv[j] = adam(layers[j], *mv[j], grad, float(t))
+                del grad
             # the head's gradient is of the sum over positions; dx is of the mean
             g_stem = add(scale(g_stem, 1.0 / n_tok), emb_bwd(stem_b, tokens, dx))
             if t == 1:
@@ -161,10 +178,9 @@ def train3(fam, cfg: dict, job: dict, seed: int, batches: list[dict], *,
         out["dp"] = leaf_norms(diff(stem, init_stem(weights.stem_key(wkey))), "stem")
         out["m"] = leaf_norms(fetch(mv_stem[0]), "stem")
         out["v"] = leaf_norms(fetch(mv_stem[1]), "stem")
-        for i in range(n_layers):
-            out["dp"].update(leaf_norms(diff(layers[i], init_layer(keys[i])),
-                                        f"layers.{i}"))
-            out["m"].update(leaf_norms(fetch(mv[i][0]), f"layers.{i}"))
-            out["v"].update(leaf_norms(fetch(mv[i][1]), f"layers.{i}"))
+        for j, (g, i, k) in enumerate(order):
+            out["dp"].update(leaf_norms(diff(layers[j], init_layer(g, k)), f"{g}.{i}"))
+            out["m"].update(leaf_norms(fetch(mv[j][0]), f"{g}.{i}"))
+            out["v"].update(leaf_norms(fetch(mv[j][1]), f"{g}.{i}"))
     log(f"reference done at {time.perf_counter() - t0:.1f} s")
     return out

@@ -1,7 +1,10 @@
 """Model FLOPs per token against hand counts."""
 
+import importlib
 import json
 import pathlib
+
+import pytest
 
 from bench import flops
 
@@ -32,3 +35,18 @@ def test_qwen2_5_3b_four_layers():
     assert flops.train_flops_per_token(load("qwen2.5-3b"), 2048) == want
     # the head is about half of the matrix weights at four layers
     assert 0.45 < 151936 * 2048 / n < 0.55
+
+
+# today's counts, by hand as above: the family's count is the one mfu reads
+HAND = {"gpt2-paper-1b": (1024, 6 * (20 * (4 * 2048**2 + 2 * 2048 * 8192) + 50304 * 2048)
+                          + 12 * 20 * 2048 * 1024),
+        "qwen2.5-3b": (2048, 6 * (4 * (2 * 2048 * 2048 + 2 * 2048 * 256 + 3 * 2048 * 11008)
+                                  + 151936 * 2048) + 12 * 4 * 16 * 128 * 2048)}
+
+
+@pytest.mark.parametrize("name", sorted(HAND))
+def test_the_family_counts_the_committed_configs_as_before(name):
+    cfg = load(name)
+    fam = importlib.import_module(f"bench.reference.{cfg['family']}")
+    seq, want = HAND[name]
+    assert fam.flops_per_token(cfg, seq) == want
